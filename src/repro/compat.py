@@ -1,69 +1,37 @@
-"""JAX version compatibility layer.
+"""Thin wrappers over the jax mesh / shard_map API (jax 0.9).
 
-The codebase is written against the modern public API (``jax.shard_map``,
-``jax.lax.axis_size``, ``jax.make_mesh(..., axis_types=...)``).  Older
-installs (jax 0.4.x) expose the same functionality under different names:
-
-  jax.shard_map(..., check_vma=)   -> jax.experimental.shard_map.shard_map(..., check_rep=)
-  jax.lax.axis_size(name)          -> jax.lax.psum(1, name)  (static for literals)
-  jax.make_mesh(..., axis_types=)  -> jax.make_mesh(...) (kwarg absent)
-
-Every module imports these three helpers from here instead of feature-testing
-jax locally.  The wrappers disable replication/vma checking in all versions:
-our custom_vjp adjoints intentionally produce replication patterns the
-checker cannot infer (see DESIGN.md §2).
+Every module imports these three helpers from here instead of calling jax
+directly, so the replication-check and axis-type choices live in one
+place.  The wrappers disable replication/vma checking: our custom_vjp
+adjoints intentionally produce replication patterns the checker cannot
+infer (see DESIGN.md §2).
 """
 
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 __all__ = ["shard_map", "axis_size", "make_mesh"]
 
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-if hasattr(jax.lax, "axis_size"):  # jax >= 0.6
-
-    def axis_size(axis_name) -> int:
-        return jax.lax.axis_size(axis_name)
-
-else:
-
-    def axis_size(axis_name) -> int:
-        # psum over a Python literal is evaluated statically at trace time
-        # and returns a plain int — the idiomatic 0.4.x axis-size query.
-        return jax.lax.psum(1, axis_name)
+def axis_size(axis_name) -> int:
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """jax.make_mesh with Auto axis types where the kwarg exists.
+    """jax.make_mesh with Auto axis types.
 
     ``devices`` restricts the mesh to an explicit device subset (the
     elastic mesh-shrink path builds degraded meshes over the survivors of
     a simulated device loss); None keeps jax's default device assignment.
     """
-    if hasattr(jax.sharding, "AxisType"):
-        kw = {"devices": devices} if devices is not None else {}
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
-            **kw)
-    if devices is not None:
-        return jax.sharding.Mesh(
-            np.asarray(devices).reshape(tuple(axis_shapes)),
-            tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    kw = {"devices": devices} if devices is not None else {}
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        **kw)

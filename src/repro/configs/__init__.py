@@ -6,4 +6,5 @@ from .base import (  # noqa: F401
     applicable_shapes,
     get_config,
     reduced,
+    with_layers,
 )

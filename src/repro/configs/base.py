@@ -218,6 +218,19 @@ def get_config(name: str) -> ModelConfig:
     return mod.CONFIG
 
 
+def with_layers(cfg: ModelConfig, num_layers: int) -> ModelConfig:
+    """``cfg`` cut (or grown) to ``num_layers`` layers, every width kept.
+
+    The one depth cut the launchers offer (``--layers``): a stack of
+    whole superblocks, so the layer kinds keep their published pattern.
+    """
+    if num_layers < 1 or num_layers % cfg.block_period:
+        raise ValueError(
+            f"{cfg.name}: {num_layers} layers is not a positive multiple "
+            f"of its {cfg.block_period}-layer block period")
+    return dataclasses.replace(cfg, num_layers=num_layers)
+
+
 def reduced(cfg: ModelConfig) -> ModelConfig:
     """Tiny same-family variant for CPU smoke tests: small layers/width, few
     experts, tiny vocab — exercises every structural feature of the arch."""

@@ -67,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
-                        interpret=True):
+                        interpret=False):
     """q: (B, Sq, H, hd); k/v: (B, Skv, KH, hd).  GQA via head replication
     of KV *indices* (no materialized repeat: the BlockSpec index map points
     group-mates at the same KV block)."""

@@ -1,9 +1,12 @@
 """Jit'd dispatch wrappers over the Pallas kernels.
 
 ``impl`` selects the implementation:
-  "xla"              pure-jnp path (CPU dry-run / default in this container)
+  "xla"              pure-jnp path (the reference the kernels are tested
+                     against)
   "pallas"           compiled Pallas kernel (TPU target)
   "pallas_interpret" Pallas kernel body executed in Python (CPU validation)
+  None               chosen by platform: "pallas" on a TPU backend,
+                     "pallas_interpret" anywhere else
 
 Training uses custom_vjp wrappers whose backward recomputes through the
 (differentiable) XLA oracle — the two implementations compute the same
@@ -13,7 +16,6 @@ kernel sweeps in tests/test_kernels.py pin that equivalence.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -23,11 +25,10 @@ from .flash_attention import flash_attention_fwd
 from .rmsnorm import rmsnorm_fwd
 from .ssd_scan import ssd_scan_fwd
 
-DEFAULT_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "xla")
-
-
 def _resolve(impl):
-    return impl or DEFAULT_IMPL
+    if impl is not None:
+        return impl
+    return "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
 
 
 # ---------------------------------------------------------------------------

@@ -57,7 +57,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_scr, *, L: int):
     y_ref[0, 0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
 
 
-def ssd_scan_fwd(x, dt, a_neg, Bm, Cm, *, chunk=64, interpret=True):
+def ssd_scan_fwd(x, dt, a_neg, Bm, Cm, *, chunk=64, interpret=False):
     """x: (B,S,H,P); dt: (B,S,H); a_neg: (H,); Bm/Cm: (B,S,N) -> y (B,S,H,P)."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]

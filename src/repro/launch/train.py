@@ -14,8 +14,15 @@ expert shards over the ep axis via AllToAll):
     PYTHONPATH=src python -m repro.launch.train --arch glm4-9b --reduced \
         --hybrid-mesh 2,1,2,2 --microbatches 4 --steps 20 --batch 16
 
-On this CPU container use --reduced (tiny same-family config); on real
-hardware drop it and point the mesh at the pod.  The loop is the fault-
+On a CPU host use --reduced (tiny same-family config).  On a TPU drop it
+for the published widths; ``--layers N`` cuts the depth to N layers (the
+only cut: widths stay published), e.g. phi4-mini-3.8b on one v5e chip:
+
+    PYTHONPATH=src python -m repro.launch.train --arch phi4-mini-3.8b \
+        --layers 2 --batch 4 --seq 512 --steps 5
+
+The step is jitted with the train state donated, so the old and the new
+state never occupy device memory together.  The loop is the fault-
 tolerant one from train/loop.py (atomic checkpoints, auto-resume,
 straggler monitor, SPMD-consistent non-finite skip).  ``--fault-plan``
 turns on the deterministic chaos harness (resilience/inject.py) — e.g.
@@ -28,11 +35,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+from functools import partial
 
 import jax
 
-from repro.configs import ARCH_IDS, get_config, reduced
+from repro.configs import ARCH_IDS, get_config, reduced, with_layers
 from repro.data import DataConfig, PrefetchIterator, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_hybrid_mesh
 from repro.models import init_params, init_pipeline_params
 from repro.optim import make_optimizer
@@ -43,10 +52,23 @@ from repro.train import (LoopConfig, build_hybrid_train_step,
                          init_train_state, restart_on_failure)
 
 
-def main():
+# Every train step takes the state as argument 0 and returns its successor:
+# donating it lets the update write in place.  No caller reads a state after
+# passing it to a step (the loop rebinds it, async checkpoints snapshot to
+# the host before the next step, fault injection raises before the step).
+jit_step = partial(jax.jit, donate_argnums=(0,))
+
+
+def main(argv=None):
+    """Train with the command line ``argv`` (default ``sys.argv[1:]``);
+    returns ``(state, history)`` from the supervised loop."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="glm4-9b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers (a multiple of "
+                         "the arch's block period); widths stay as "
+                         "configured")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -67,8 +89,8 @@ def main():
                     choices=("1f1b", "fill_drain"))
     ap.add_argument("--use-flash", action="store_true",
                     help="route train attention through kernels.ops."
-                         "flash_attention (REPRO_KERNEL_IMPL selects "
-                         "xla/pallas/pallas_interpret); GSPMD path only")
+                         "flash_attention (the compiled Pallas kernel on a "
+                         "TPU, the interpreter elsewhere); GSPMD path only")
     ap.add_argument("--fault-plan", default=None, metavar="SPEC",
                     help="inject deterministic faults (resilience/inject.py)"
                          ": comma-separated tokens, e.g. 'poison=5,crash=9,"
@@ -93,11 +115,14 @@ def main():
                          "accumulation (loss-exact), resume; requires "
                          "--hybrid-mesh")
     ap.add_argument("--max-restarts", type=int, default=3)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    if args.layers is not None:
+        cfg = with_layers(cfg, args.layers)
     n_dev = len(jax.devices())
     hybrid = None
     if args.hybrid_mesh:
@@ -154,9 +179,9 @@ def main():
             pol = Policy.for_mesh(m, explicit_tp=tp > 1)
             kw = dict(num_microbatches=args.microbatches,
                       schedule=args.schedule, virtual_dp=vdp)
-            s = jax.jit(build_hybrid_train_step(cfg, pol, opt, **kw))
-            p = (jax.jit(build_hybrid_train_step(cfg, pol, opt,
-                                                 fault_hook=hook, **kw))
+            s = jit_step(build_hybrid_train_step(cfg, pol, opt, **kw))
+            p = (jit_step(build_hybrid_train_step(cfg, pol, opt,
+                                                  fault_hook=hook, **kw))
                  if hook is not None else None)
 
             def mk():
@@ -178,26 +203,26 @@ def main():
                           else f"{k}={v}" for k, v in hist.health.items())
         print(f"done: final loss {hist[-1]['loss']!r} over {len(hist)} "
               f"steps  [{health}]")
-        return
+        return state, hist
 
     if hybrid:
-        step = jax.jit(build_hybrid_train_step(
+        step = jit_step(build_hybrid_train_step(
             cfg, policy, opt, num_microbatches=args.microbatches,
             schedule=args.schedule))
     else:
-        step = jax.jit(build_train_step(cfg, policy, opt,
-                                        use_flash=args.use_flash))
+        step = jit_step(build_train_step(cfg, policy, opt,
+                                         use_flash=args.use_flash))
     if plan is not None:
         # the poisoned sibling is a second compiled variant of the SAME
         # builder with the gradient fault hook traced in; the injector
         # chooses between them on the host (fire-once across restarts)
         hook = nan_grad_hook(plan.poison_value)
         if hybrid:
-            poisoned = jax.jit(build_hybrid_train_step(
+            poisoned = jit_step(build_hybrid_train_step(
                 cfg, policy, opt, num_microbatches=args.microbatches,
                 schedule=args.schedule, fault_hook=hook))
         else:
-            poisoned = jax.jit(build_train_step(
+            poisoned = jit_step(build_train_step(
                 cfg, policy, opt, use_flash=args.use_flash, fault_hook=hook))
         step = FaultInjector(plan, step, poisoned_step_fn=poisoned,
                              ckpt_dir=args.ckpt_dir)
@@ -218,6 +243,7 @@ def main():
                       for k, v in hist.health.items())
     print(f"done: final loss {hist[-1]['loss']!r} over {len(hist)} steps  "
           f"[{health}]")
+    return state, hist
 
 
 if __name__ == "__main__":

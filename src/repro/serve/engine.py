@@ -68,17 +68,28 @@ class ServeEngine:
         return self._decode(self.params, cache, tokens, cache_len)
 
     def generate(self, prompt, steps: int, *, greedy: bool = True, key=None,
-                 temperature: float = 1.0):
-        """Greedy / temperature sampling for ``steps`` tokens."""
+                 temperature: float = 1.0, return_logits: bool = False):
+        """Greedy / temperature sampling for ``steps`` tokens.
+
+        With ``return_logits`` also returns the ``(B, steps + 1, V)``
+        logits the engine computed: entry ``t`` is the prefill's last
+        position for ``t = 0`` and decode step ``t - 1`` after that — the
+        cached counterparts of an uncached forward's positions
+        ``S - 1 .. S - 1 + steps``.
+        """
         B, S = prompt.shape
         logits, cache = self.prefill(prompt)
-        out = []
+        out, seen = [], [logits]
         tok = self._pick(logits, greedy, key, temperature, 0)
         for t in range(steps):
             out.append(tok)
             logits, cache = self.decode_step(cache, tok, jnp.int32(S + t))
+            seen.append(logits)
             tok = self._pick(logits, greedy, key, temperature, t + 1)
-        return jnp.concatenate(out, axis=1)
+        tokens = jnp.concatenate(out, axis=1)
+        if return_logits:
+            return tokens, jnp.stack(seen, axis=1)
+        return tokens
 
     @staticmethod
     def _pick(logits, greedy, key, temperature, t):

@@ -149,6 +149,10 @@ def run(state, train_step, data_iter, loop_cfg: LoopConfig, *, logger=print,
 # float traps.  Programming errors (TypeError, ValueError, KeyError...)
 # stay fatal — restarting can't fix those and the retry would loop.
 RECOVERABLE = (RuntimeError, OSError, FloatingPointError)
+# Device faults (out of memory, a failed launch or compile) are
+# RuntimeErrors too, but the same step on the same device raises them
+# again: they propagate at once instead of burning every restart.
+FATAL = (jax.errors.JaxRuntimeError,)
 
 
 def restart_on_failure(make_state, train_step, make_data_iter,
@@ -167,7 +171,8 @@ def restart_on_failure(make_state, train_step, make_data_iter,
     :class:`NonFiniteStreakError` (persistent poison): additionally advance
     the stateless data iterator past the poisoned window via
     ``data_offset``.  Raises after ``max_restarts`` recoveries; exception
-    types outside ``recoverable`` propagate immediately.  Returns
+    types outside ``recoverable``, and device faults (``FATAL``),
+    propagate immediately.  Returns
     ``(state, history)``, ``history.health`` carrying restart/rollback/
     skip/backoff/quarantine counters across all attempts.
     """
@@ -206,6 +211,8 @@ def restart_on_failure(make_state, train_step, make_data_iter,
                    f"(restart {restarts}/{max_restarts})")
             if restarts >= max_restarts:
                 raise
+        except FATAL:
+            raise
         except recoverable as e:
             restarts += 1
             history.health["restarts"] += 1
@@ -308,6 +315,8 @@ def elastic_restart_on_failure(make_setup, make_data_iter,
                    f"(restart {restarts}/{max_restarts})")
             if restarts >= max_restarts:
                 raise
+        except FATAL:
+            raise
         except recoverable as e:
             restarts += 1
             history.health["restarts"] += 1

@@ -7,8 +7,6 @@ import sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
-# tests/ itself, for the shared hypothesis_compat shim (the fuzzer).
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax  # noqa: E402
 import pytest  # noqa: E402

@@ -21,12 +21,17 @@ meshes (axis sizes 8, 2, 4); with 1 device every axis degenerates to size
 """
 
 import jax
-from hypothesis_compat import HealthCheck, given, settings, strategies as st
+import pytest
+from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro import compat
 from repro.analysis import spaces
 from repro.core import linop
 from repro.core.linop import Space, check_adjoint
+
+# ~170 s on 8 emulated devices: the CI slow leg runs it, not tier-1
+# (tests/md/test_linop.py keeps every op and composite there)
+pytestmark = pytest.mark.slow
 
 MAX_DIM = 256          # cap local growth (all_gather/grad_sum_reduce x k)
 N_EXAMPLES = 60        # >= 50 random composites per CI run

@@ -164,7 +164,7 @@ def test_cross_mesh_restore_lands_in_golden_family(tmp_path):
     degenerate mesh, step 2 within rtol 1e-4 of the pinned value."""
     _need8()
     from repro.checkpoint import ckpt as ckpt_lib
-    golden = (6.103421211242676, 5.887178421020508)   # hybrid_cp_2x1x2x2
+    golden = (5.932372093200684, 5.708051681518555)   # hybrid_cp_2x1x2x2
     opt = make_optimizer("adamw", total_steps=10)
     key = jax.random.PRNGKey(1)
     b = {"tokens": jax.random.randint(key, (16, 16), 0, CFG.vocab_size),
@@ -197,9 +197,11 @@ def test_elastic_cli_end_to_end(tmp_path):
     import sys
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    # the CLI turns the persistent compile cache on; tests keep it off
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(root, "src"))
+               PYTHONPATH=os.path.join(root, "src"),
+               JAX_ENABLE_COMPILATION_CACHE="false")
     base = [sys.executable, "-m", "repro.launch.train", "--arch", "glm4-9b",
             "--reduced", "--hybrid-mesh", "2,1,2,2", "--microbatches", "4",
             "--steps", "8", "--batch", "16", "--seq", "64"]

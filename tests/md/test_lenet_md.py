@@ -11,6 +11,9 @@ from repro.models.lenet import (lenet_apply_distributed,
                                 lenet_apply_sequential, lenet_init,
                                 synthetic_mnist, table1_local_shapes)
 
+# ~230 s on 8 emulated devices: the CI slow leg runs it, not tier-1
+pytestmark = pytest.mark.slow
+
 
 @pytest.fixture(scope="module")
 def mesh22():

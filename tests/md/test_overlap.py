@@ -3,9 +3,13 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import overlap, primitives as prim
+
+# ~100 s on 8 emulated devices: the CI slow leg runs it, not tier-1
+pytestmark = pytest.mark.slow
 
 
 def _r(shape, seed=0):

@@ -10,8 +10,9 @@ still ring-rotates correctly; and the compiled CP train step contains NO
 sequence-dim all-gather (the SP->TP gather the ring eliminates) while the
 SP baseline does.
 
-The heavyweight compile-bound tests are marked ``slow`` (run by the CI
-ctx-live leg); the default md run keeps the (2, 1, 2, 2) smoke.
+The whole file is marked ``slow`` (about 530 s on 8 emulated devices):
+the CI slow leg runs it, and the tier-1 md subprocess keeps (2, 1, 2, 2)
+CP coverage through ``test_golden.py``'s ``hybrid_cp_2x1x2x2``.
 """
 
 import jax
@@ -35,6 +36,8 @@ from test_hybrid import (CFG, _assert_matches_reference, _assert_trees_close,
                          _data)
 
 from jax.sharding import PartitionSpec as P
+
+pytestmark = pytest.mark.slow
 
 
 def _need8():
@@ -139,7 +142,6 @@ def _cp_loss_and_grads(mesh, M, *, explicit_tp=True, pparams=None,
 
 
 class TestCPMatchesReference:
-    @pytest.mark.slow
     def test_2dp_1stage_2cp_2tp(self):
         """Acceptance: (dp, pp, cp, tp) = (2, 1, 2, 2) vs the fp32
         single-device loss and EVERY parameter gradient."""
@@ -147,14 +149,12 @@ class TestCPMatchesReference:
         _assert_matches_reference(
             *_cp_loss_and_grads(make_hybrid_mesh(2, 1, 2, 2), M=4))
 
-    @pytest.mark.slow
     def test_1dp_1stage_4cp_2tp(self):
         """Acceptance: (1, 1, 4, 2) — a deeper ring, same reference."""
         _need8()
         _assert_matches_reference(
             *_cp_loss_and_grads(make_hybrid_mesh(1, 1, 4, 2), M=4))
 
-    @pytest.mark.slow
     def test_cp_without_tp(self):
         """(2, 1, 4, 1): the non-explicit stage-body branch also rings."""
         _need8()
@@ -162,7 +162,6 @@ class TestCPMatchesReference:
             *_cp_loss_and_grads(make_hybrid_mesh(2, 1, 4, 1), M=4,
                                 explicit_tp=False))
 
-    @pytest.mark.slow
     def test_cp_composes_with_pipe(self):
         """(1, 2, 2, 2): ctx rings inside pipeline stage bodies."""
         _need8()
@@ -221,7 +220,6 @@ class TestDegenerateCP:
 
 
 class TestFusedTPRing:
-    @pytest.mark.slow
     def test_gspmd_explicit_tp_with_ctx(self):
         """forward() on a (data, ctx, model) mesh with explicit_tp: the
         fused dist_jit sublayer keeps the seq dim ctx-sharded at its
@@ -288,7 +286,6 @@ class TestGQARotation:
 # ---------------------------------------------------------------------------
 
 class TestCompiledHLO:
-    @pytest.mark.slow
     def test_no_seq_allgather_under_cp(self):
         """The SP baseline's compiled train step all-gathers the sequence
         dim in the attention region; the CP program must not — and its

@@ -137,7 +137,10 @@ class TestSSDScan:
 
 
 class TestRMSNorm:
-    @pytest.mark.parametrize("shape", [(4, 256), (2, 8, 512), (128, 1024)])
+    # 250 and 2 x 150 rows: counts the 128-row block does not divide (a
+    # partial last block), and counts below it (one whole-rows block)
+    @pytest.mark.parametrize("shape", [(4, 256), (2, 8, 512), (128, 1024),
+                                       (250, 256), (2, 150, 128)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, shape, dtype):
         x = _r(shape, 0, dtype)
@@ -147,3 +150,19 @@ class TestRMSNorm:
         tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
         np.testing.assert_allclose(out.astype(jnp.float32),
                                    want.astype(jnp.float32), atol=tol, rtol=tol)
+
+    def test_block_rows_must_tile_sublanes(self):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            rmsnorm_fwd(_r((64, 128), 0), _r((128,), 1), block_rows=100,
+                        interpret=True)
+
+
+def test_default_impl_follows_platform():
+    """impl=None runs the compiled kernel on a TPU and the interpreter
+    elsewhere; no environment variable chooses a silent XLA fallback."""
+    want = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    assert ops._resolve(None) == want
+    assert ops._resolve("xla") == "xla"
+    x, w = _r((16, 128), 2), _r((128,), 3)
+    np.testing.assert_allclose(ops.rmsnorm(x, w), ref.rmsnorm_ref(x, w),
+                               atol=1e-5, rtol=1e-5)

@@ -2,7 +2,7 @@
 
 import jax
 import jax.numpy as jnp
-from hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import adjoint_test
 from repro.core import memory as mem

@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import DataConfig, SyntheticLM
 from repro.optim import (Adafactor, AdamW, clip_by_global_norm,

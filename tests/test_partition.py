@@ -1,7 +1,7 @@
 """Partition + halo geometry vs the paper's Appendix B worked examples."""
 
 import numpy as np
-from hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partition import (
     TensorPartition,

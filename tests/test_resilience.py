@@ -22,7 +22,8 @@ from repro.resilience import (FaultInjector, FaultPlan, InjectedCrash,
                               corrupt_checkpoint, nan_grad_hook,
                               nonfinite_count, nonfinite_flag, tree_where)
 from repro.train import (LoopConfig, NonFiniteStreakError, build_train_step,
-                         init_train_state, restart_on_failure, run)
+                         elastic_restart_on_failure, init_train_state,
+                         restart_on_failure, run)
 
 TOTAL = 12
 
@@ -235,6 +236,33 @@ def test_unrecoverable_exception_propagates(rig, tmp_path):
         restart_on_failure(rig["make_state"], bad_step, rig["make_iter"],
                            loop_cfg, backoff_base=0.01,
                            logger=lambda *a: None)
+
+
+@pytest.mark.parametrize("elastic", [False, True])
+def test_device_fault_propagates_without_restart(rig, tmp_path, elastic):
+    """A device fault (out of memory, failed launch) is a RuntimeError,
+    but replaying the step raises it again: it surfaces on the first
+    occurrence, with no restart spent on it."""
+    calls = []
+
+    def oom_step(state, batch):
+        calls.append(1)
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    loop_cfg = LoopConfig(total_steps=TOTAL, ckpt_dir=str(tmp_path / "c"),
+                          log_every=1000)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        if elastic:
+            elastic_restart_on_failure(
+                lambda fact, devices, vdp: (None, rig["make_state"],
+                                            oom_step, None),
+                rig["make_iter"], loop_cfg, factorization=(1, 1, 1, 1, 1),
+                backoff_base=0.01, logger=lambda *a: None)
+        else:
+            restart_on_failure(rig["make_state"], oom_step, rig["make_iter"],
+                               loop_cfg, backoff_base=0.01,
+                               logger=lambda *a: None)
+    assert len(calls) == 1
 
 
 def test_corrupt_checkpoint_targets_named_array(rig, tmp_path):
