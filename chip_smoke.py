@@ -51,8 +51,8 @@ PROMPT, NEW_TOKENS = 128, 32
 # of the residual stream (embedding, 2 layers x 2 sublayers, final norm),
 # so they may differ by ~6 x 2^-8 = 2.3 % of the logit scale with no
 # fault (a CPU rehearsal at d_model 1536 showed 1.0-1.4 %; in fp32 the
-# two paths agree to 1e-6).  A cache fault (wrong slot, wrong position, stale entry) moves
-# the logits by their whole scale.
+# two paths agree to 1e-6).  A cache fault (wrong slot, wrong position,
+# stale entry) moves the logits by their whole scale.
 LOGIT_RTOL = 3e-2
 HBM_BYTES = 16 * 10**9          # one v5e chip
 GOLDEN_RTOL = 1e-4              # tests/md/test_golden.py
