@@ -1,0 +1,171 @@
+"""The harness end to end at a tiny size on the CPU, with the look for a
+chip skipped: a sound run is ``correct``, and each fault a cell can have,
+planted in the timed path underneath, makes it not correct under the
+cells' own limits; so does the control (the reference with float8
+operands in the program's place).  Also the command line without a chip,
+and the data files every entry of BENCHMARK.json needs."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from bench import common
+from bench import run as harness
+
+TINY = {"name": "tiny", "hidden_size": 64, "intermediate_size": 128,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+        "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 1e6,
+        "rms_norm_eps": 1e-6, "torch_dtype": "bfloat16"}
+BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+ENTRY = {w["name"]: w for w in BENCH["workloads"]}
+# The training cell's files, kept for the cell that is out of
+# BENCHMARK.json until the program's decay rule is mended (PERF.md).  At
+# this size the first clipped gradient reads ~0.015 element-wise against
+# the reference, the float8 control ~0.26, half the batch ~0.89, and a
+# state left unchanged 1; the gradient of the first step is untouched by
+# the program's decay of its norm scales, which moves later changes only.
+TRAIN = {"name": "phi4-train-b4s512", "config": "phi4-mini-3.8b-2L",
+         "traffic": "train-b4s512", "chips": 1}
+TRAIN_LIMITS = {"grad_err": 0.06}
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache(monkeypatch):
+    import repro.launch.compile_cache as cc
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: None)
+
+
+def train(calibrate=""):
+    traffic = dict(harness.load("traffic", TRAIN["traffic"]), batch=4, seq=32)
+    config = dict(TINY, arch="phi4-mini-3.8b", tie_word_embeddings=True)
+    return harness.run_cell(TRAIN, 2**31 + 11, 0.5, False, calibrate=calibrate,
+                            files={"config": config, "traffic": traffic,
+                                   "cell": {"limits": TRAIN_LIMITS}})
+
+
+def serve(calibrate=""):
+    entry = ENTRY["glm4-prefill-4k"]
+    traffic = dict(harness.load("traffic", entry["traffic"]), batch=2,
+                   prompt=16, new_tokens=8, max_seq=32)
+    config = dict(TINY, arch="glm4-9b", tie_word_embeddings=False)
+    return harness.run_cell(entry, 2**31 + 12, 0.5, False, calibrate=calibrate,
+                            files={"config": config, "traffic": traffic})
+
+
+def fails(numbers, limits):
+    return not common.judge(numbers, limits)[0]
+
+
+def test_train_sound_and_control():
+    rec = train(calibrate="control")
+    assert rec.correct, rec.checks
+    assert fails(rec.control, rec.cell["limits"]), rec.control
+    assert fails(rec.half_batch, rec.cell["limits"]), rec.half_batch
+
+
+def _broken_builder(monkeypatch, fault):
+    import repro.train as train_lib
+    real_builder = train_lib.build_train_step
+
+    def builder(*a, **k):
+        real = real_builder(*a, **k)
+
+        def step(state, batch):
+            if fault == "unchanged":
+                new, met = real(state, batch)
+                return dict(state, step=new["step"]), met
+            half = {n: v[: v.shape[0] // 2] for n, v in batch.items()}
+            return real(state, half)
+        return step
+
+    monkeypatch.setattr(train_lib, "build_train_step", builder)
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_train_fault_is_not_correct(monkeypatch, fault):
+    _broken_builder(monkeypatch, fault)
+    rec = train()
+    assert rec.steps > 0 and rec.window_s > 0
+    assert not rec.correct, rec.checks
+
+
+def test_serve_sound_and_control():
+    rec = serve(calibrate="control")
+    assert rec.correct, rec.checks
+    assert rec.compared > 0 and rec.tokens > 0
+    assert fails(rec.control, rec.cell["limits"]), rec.control
+
+
+def test_serve_altered_token_is_not_correct(monkeypatch):
+    from repro.serve import ServeEngine
+    real = ServeEngine._pick
+
+    def altered(logits, *a):
+        tok = real(logits, *a)
+        return tok.at[0].set((tok[0] + 1) % logits.shape[-1]).astype(jnp.int32)
+
+    monkeypatch.setattr(ServeEngine, "_pick", staticmethod(altered))
+    rec = serve()
+    assert not rec.correct, rec.checks
+
+
+def test_serve_stale_cache_is_not_correct(monkeypatch):
+    """A decode step that hands back the cache it was given: later
+    tokens attend to slots that were never written."""
+    from repro.serve import ServeEngine
+    real = ServeEngine.decode_step
+
+    def stale(self, cache, tokens, cache_len):
+        kept = jax.tree_util.tree_map(jnp.copy, cache)  # the step donates it
+        logits, _ = real(self, cache, tokens, cache_len)
+        return logits, kept
+
+    monkeypatch.setattr(ServeEngine, "decode_step", stale)
+    rec = serve()
+    assert not rec.correct, rec.checks
+
+
+def test_command_line_without_a_chip_prints_no_result():
+    out = subprocess.run(
+        [sys.executable, str(harness.BENCH / "run.py"), "--workload",
+         BENCH["workloads"][0]["name"], "--seed", "0", "--seconds", "10",
+         "--trace", "0"],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr
+    assert "{" not in out.stdout
+
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def test_every_entry_has_its_files():
+    for c in BENCH["configs"]:
+        assert NAME.match(c["name"])
+        cfg = harness.load("configs", c["name"])
+        assert cfg["name"] == c["name"]
+        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        for key in ("source", "reduced", "assumed", "deployment"):
+            assert cfg[key], key
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert harness.load("workloads", w["name"])["limits"]
+        traffic = harness.load("traffic", w["traffic"])
+        assert (harness.BENCH / "drivers" / f"{traffic['driver']}.py").exists()
+    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert harness.reader(m["name"]).exists(), m["name"]
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        for w in m["workloads"]:
+            assert m["moves"] in {
+                x["name"] for x in harness.metric_names(BENCH, w, False)}
