@@ -184,11 +184,13 @@ def attention_block(p, x, cfg, policy, *, positions, mode, cache=None,
     ``positions`` carry global positions, and the ring runs directly.
     """
     hd = cfg.resolved_head_dim
-    q = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wq"]), cfg.num_heads, hd)
-    k = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wk"]), cfg.num_kv_heads, hd)
-    v = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wv"]), cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn_qkv"):
+        q = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wq"]), cfg.num_heads, hd)
+        k = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wk"]), cfg.num_kv_heads, hd)
+        v = _split_heads(jnp.einsum("bsd,dh->bsh", x, p["wv"]), cfg.num_kv_heads, hd)
+    with jax.named_scope("attn_rope"):
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     ring_gspmd = (policy is not None and mode == "train"
                   and policy.active_ctx_axis is not None and ctx_axis is None)
@@ -219,22 +221,23 @@ def attention_block(p, x, cfg, policy, *, positions, mode, cache=None,
 
     new_cache = None
     if mode in ("train", "prefill"):
-        if ctx_axis is not None and mode == "train":
-            # SPMD-side ring: already inside a manual region (pipeline
-            # stage body) with local sequence shards.
-            from repro.core.ring_attention import ring_attention
-            out = ring_attention(q, k, v, ctx_axis, chunk=cfg.attn_chunk,
-                                 unroll=cfg.unroll_scans)
-        elif ring_gspmd:
-            from repro.core.ring_attention import ring_attention_gspmd
-            out = ring_attention_gspmd(q, k, v, policy, chunk=cfg.attn_chunk,
-                                       unroll=cfg.unroll_scans)
-        elif use_flash:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=True)
-        else:
-            out = blockwise_attention(q, k, v, chunk=cfg.attn_chunk,
-                                      unroll=cfg.unroll_scans)
+        with jax.named_scope("attn_core"):
+            if ctx_axis is not None and mode == "train":
+                # SPMD-side ring: already inside a manual region (pipeline
+                # stage body) with local sequence shards.
+                from repro.core.ring_attention import ring_attention
+                out = ring_attention(q, k, v, ctx_axis, chunk=cfg.attn_chunk,
+                                     unroll=cfg.unroll_scans)
+            elif ring_gspmd:
+                from repro.core.ring_attention import ring_attention_gspmd
+                out = ring_attention_gspmd(q, k, v, policy, chunk=cfg.attn_chunk,
+                                           unroll=cfg.unroll_scans)
+            elif use_flash:
+                from repro.kernels import ops as kops
+                out = kops.flash_attention(q, k, v, causal=True)
+            else:
+                out = blockwise_attention(q, k, v, chunk=cfg.attn_chunk,
+                                          unroll=cfg.unroll_scans)
         if mode == "prefill":
             if policy is not None:
                 k = policy.constrain(k, "batch", None, None, "kvdim")
@@ -243,18 +246,21 @@ def attention_block(p, x, cfg, policy, *, positions, mode, cache=None,
     else:  # decode
         assert cache is not None
         idx = jnp.reshape(cache_len, ())
-        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
-        if policy is not None:
-            if getattr(policy, "kv_layout", "kvdim") == "kvseq":
-                k_cache = policy.constrain(k_cache, "batch", "kvseq", None, None)
-                v_cache = policy.constrain(v_cache, "batch", "kvseq", None, None)
-            else:
-                k_cache = policy.constrain(k_cache, "batch", None, None, "kvdim")
-                v_cache = policy.constrain(v_cache, "batch", None, None, "kvdim")
-        out = decode_attention(q, k_cache, v_cache, idx + 1)
+        with jax.named_scope("attn_cache"):
+            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
+            if policy is not None:
+                if getattr(policy, "kv_layout", "kvdim") == "kvseq":
+                    k_cache = policy.constrain(k_cache, "batch", "kvseq", None, None)
+                    v_cache = policy.constrain(v_cache, "batch", "kvseq", None, None)
+                else:
+                    k_cache = policy.constrain(k_cache, "batch", None, None, "kvdim")
+                    v_cache = policy.constrain(v_cache, "batch", None, None, "kvdim")
+        with jax.named_scope("attn_core"):
+            out = decode_attention(q, k_cache, v_cache, idx + 1)
         new_cache = {"k": k_cache, "v": v_cache}
 
-    out = out.reshape(out.shape[0], out.shape[1], cfg.num_heads * hd)
-    out = jnp.einsum("bsh,hd->bsd", out, p["wo"])
+    with jax.named_scope("attn_out"):
+        out = out.reshape(out.shape[0], out.shape[1], cfg.num_heads * hd)
+        out = jnp.einsum("bsh,hd->bsd", out, p["wo"])
     return out, new_cache

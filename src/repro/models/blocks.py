@@ -132,28 +132,36 @@ def sublayer_apply(p, x, cfg, policy, layer: int, *, positions, mode,
         x = policy.constrain(x, "batch", "seq", None)
         return x, None, aux
 
-    h = rmsnorm(x, p["norm_mixer"])
+    # Each residual add sits in the scope of the product it joins: XLA
+    # fuses the two, and a fusion is charged to its root's scope.
+    with jax.named_scope("norm"):
+        h = rmsnorm(x, p["norm_mixer"])
     if mixer == "attn":
         out, new_cache = attention_block(
             p["attn"], h, cfg, policy, positions=positions, mode=mode,
             cache=cache, cache_len=cache_len, use_flash=use_flash,
             ctx_axis=ctx_axis)
+        with jax.named_scope("attn_out"):
+            x = x + out
     else:
         out, new_cache = ssm_block(p["ssm"], h, cfg, policy, mode=mode,
                                    cache=cache)
-    x = x + out
+        x = x + out
     if policy is not None and mode != "decode":
         x = policy.constrain(x, "batch", "seq", None)
 
     if ffn != "none":
-        h = rmsnorm(x, p["norm_ffn"])
+        with jax.named_scope("norm"):
+            h = rmsnorm(x, p["norm_ffn"])
         if ffn == "mlp":
-            out = mlp_apply(h, p["mlp"], cfg.mlp_type)
-            if policy is not None and mode != "decode":
-                out = policy.constrain(out, "batch", "seq", None)
+            with jax.named_scope("ffn"):
+                out = mlp_apply(h, p["mlp"], cfg.mlp_type)
+                if policy is not None and mode != "decode":
+                    out = policy.constrain(out, "batch", "seq", None)
+                x = x + out
         else:
             out, aux = moe_apply(h, p["moe"], cfg, policy)
-        x = x + out
+            x = x + out
         if policy is not None and mode != "decode":
             x = policy.constrain(x, "batch", "seq", None)
     return x, new_cache, aux

@@ -1,4 +1,28 @@
-"""Shared model components: norms, RoPE, MLPs, initialization."""
+"""Shared model components: norms, RoPE, MLPs, initialization.
+
+Named scopes.  The forward puts each of its sublayers under one flat
+``jax.named_scope`` name, so that a device trace can be charged to them
+(the names live in the ops' metadata only: they change no computation).
+
+- ``embed``: the token lookup (``model.forward``);
+- ``layers``: the scan over the stacked layers; the scan's own slicing of
+  the stacked weights and caches, its stacking of the new caches and its
+  loop counter carry no inner name;
+- in the layer body (``blocks.sublayer_apply``, ``attention.attention_block``):
+  ``norm`` the two RMSNorms; ``attn_qkv`` the q/k/v projections;
+  ``attn_rope`` the rotary embedding; ``attn_cache`` decode's writes of the
+  new key and value into the cache; ``attn_core`` the attention contraction
+  (blockwise, decode, flash or ring); ``attn_out`` the output projection
+  and the residual add that joins it; ``ffn`` the dense MLP and its
+  residual add;
+- ``final_norm`` and ``head``: the last RMSNorm and the vocabulary
+  projection (``model.forward``; the serve engine's pick of the last
+  position is ``head`` too);
+- ``cache_init``: the serve engine's preallocated cache and the prefill's
+  write into it (``serve.engine.ServeEngine``).
+
+MoE, SSM, the fused tensor-parallel body and the optimizer carry none yet.
+"""
 
 from __future__ import annotations
 
@@ -7,6 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import primitives as prim
+
+SCOPES = ("embed", "layers", "norm", "attn_qkv", "attn_rope", "attn_cache",
+          "attn_core", "attn_out", "ffn", "final_norm", "head", "cache_init")
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -237,15 +237,16 @@ def forward(params, batch, cfg, policy=None, *, mode="train", cache=None,
     batch: {"tokens": (B,S) int32} or {"embeds": (B,S,d)} for stub
     frontends; decode additionally takes {"cache_len": ()} and S == 1.
     """
-    if "embeds" in batch:
-        x = batch["embeds"]
-        B, S = x.shape[:2]
-    else:
-        tokens = batch["tokens"]
-        B, S = tokens.shape
-        x = jnp.take(params["embed"], tokens, axis=0)
     dtype = jnp.dtype(cfg.dtype)
-    x = x.astype(dtype)
+    with jax.named_scope("embed"):
+        if "embeds" in batch:
+            x = batch["embeds"]
+            B, S = x.shape[:2]
+        else:
+            tokens = batch["tokens"]
+            B, S = tokens.shape
+            x = jnp.take(params["embed"], tokens, axis=0)
+        x = x.astype(dtype)
 
     cache_len = batch.get("cache_len", jnp.zeros((), jnp.int32))
     if mode == "decode":
@@ -273,20 +274,23 @@ def forward(params, batch, cfg, policy=None, *, mode="train", cache=None,
     cache_xs = cache if cache is not None else {
         f"pos{i}": None for i in range(cfg.block_period)}
 
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params["blocks"], cache_xs),
-        unroll=cfg.unroll_scans)
+    with jax.named_scope("layers"):
+        (x, aux), new_cache = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), (params["blocks"], cache_xs),
+            unroll=cfg.unroll_scans)
 
-    x = rmsnorm(x, params["norm_final"])
-    head = params.get("lm_head")
-    if head is None:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, head)
-    if policy is not None:
-        # vocab owns the model axis here; the seq dim stays replicated
-        # under plain SP ('seq' and 'vocab' map to the same physical axis)
-        # but rides the ctx axis under context parallelism — "ctx" resolves
-        # replicated when no ctx axis is live, so cp=1 is unchanged.
-        logits = policy.constrain(logits, "batch", "ctx", "vocab")
+    with jax.named_scope("final_norm"):
+        x = rmsnorm(x, params["norm_final"])
+    with jax.named_scope("head"):
+        head = params.get("lm_head")
+        if head is None:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, head)
+        if policy is not None:
+            # vocab owns the model axis here; the seq dim stays replicated
+            # under plain SP ('seq' and 'vocab' map to the same physical axis)
+            # but rides the ctx axis under context parallelism — "ctx" resolves
+            # replicated when no ctx axis is live, so cp=1 is unchanged.
+            logits = policy.constrain(logits, "batch", "ctx", "vocab")
     return logits, new_cache, aux

@@ -40,8 +40,6 @@ class ServeEngine:
     def _prefill_impl(self, params, batch):
         logits, pref_cache, _ = forward(params, batch, self.cfg, self.policy,
                                         mode="prefill")
-        big = init_cache(self.cfg, self.batch_size, self.max_seq,
-                         jnp.dtype(self.cfg.dtype))
 
         def write(dst, src):
             if dst.ndim >= 3 and dst.shape[2] == self.max_seq:
@@ -49,16 +47,41 @@ class ServeEngine:
                                                            0, axis=2)
             return src.astype(dst.dtype)   # ssm state / conv state: final
 
-        cache = jax.tree_util.tree_map(write, big, pref_cache)
-        return logits[:, -1], cache
+        with jax.named_scope("cache_init"):
+            big = init_cache(self.cfg, self.batch_size, self.max_seq,
+                             jnp.dtype(self.cfg.dtype))
+            cache = jax.tree_util.tree_map(write, big, pref_cache)
+        with jax.named_scope("head"):
+            return logits[:, -1], cache
 
     def _decode_impl(self, params, cache, tokens, cache_len):
         batch = {"tokens": tokens, "cache_len": cache_len}
         logits, cache, _ = forward(params, batch, self.cfg, self.policy,
                                    mode="decode", cache=cache)
-        return logits[:, -1], cache
+        with jax.named_scope("head"):
+            return logits[:, -1], cache
 
     # -- public API ------------------------------------------------------------
+    def lower(self, prompt_len: int) -> dict:
+        """The engine's two programs lowered for prompts of ``prompt_len``
+        tokens, from shapes alone: ``{"prefill": ..., "decode": ...}``
+        (``jax.stages.Lowered``; ``.compile().as_text()`` is the program
+        as it runs)."""
+        def shape(a):     # a sharding over devices is part of the program
+            s = getattr(a, "sharding", None)
+            spread = s is not None and len(s.device_set) > 1
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=s if spread else None)
+
+        params = jax.tree_util.tree_map(shape, self.params)
+        batch = {"tokens": jax.ShapeDtypeStruct((self.batch_size, prompt_len),
+                                                jnp.int32)}
+        _, cache = jax.eval_shape(self._prefill, params, batch)
+        tokens = jax.ShapeDtypeStruct((self.batch_size, 1), jnp.int32)
+        cache_len = jax.ShapeDtypeStruct((), jnp.int32)
+        return {"prefill": self._prefill.lower(params, batch),
+                "decode": self._decode.lower(params, cache, tokens, cache_len)}
+
     def prefill(self, tokens):
         """tokens: (B, S_prompt) -> (last_logits, cache)."""
         return self._prefill(self.params, {"tokens": tokens})

@@ -37,6 +37,26 @@ def test_compile_cache_placement(monkeypatch):
     assert got.endswith(".jax_cache")
 
 
+def test_compile_log_records_each_compile():
+    import time
+
+    compile_cache.log_compiles()
+    compile_cache.log_compiles()                 # one listener, not two
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda x: x * 3 + 1)
+    before = len(compile_cache.compiles())
+    t0 = time.perf_counter()
+    f(x).block_until_ready()
+    t1 = time.perf_counter()
+    new = compile_cache.compiles()[before:]
+    assert len(new) == 1
+    name, start, end = new[0]
+    assert "lambda" in name
+    assert t0 - 0.01 <= start <= end <= t1 + 0.01
+    f(x).block_until_ready()                     # cached: no compile
+    assert len(compile_cache.compiles()) == before + 1
+
+
 def test_with_layers_cuts_depth_only():
     cfg = get_config("phi4-mini-3.8b")
     cut = with_layers(cfg, 2)

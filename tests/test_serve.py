@@ -70,3 +70,37 @@ def test_ssm_generation():
         expect = jnp.argmax(logits[:, 12 + t - 1], axis=-1)
         np.testing.assert_array_equal(np.asarray(out[:, t]),
                                       np.asarray(expect))
+
+
+def test_programs_carry_the_scope_vocabulary(setup):
+    """Every named scope of the forward's vocabulary reaches the compiled
+    serve programs' op metadata: the cache write in decode only, the
+    cache set-up in prefill only."""
+    import re
+
+    from repro.models.common import SCOPES
+
+    _, _, engine = setup
+    found = {}
+    for name, lowered in engine.lower(16).items():
+        paths = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+        found[name] = {p for path in paths for p in path.split("/")} & set(SCOPES)
+    assert found["prefill"] | found["decode"] == set(SCOPES)
+    assert "attn_cache" in found["decode"] and "attn_cache" not in found["prefill"]
+    assert "cache_init" in found["prefill"] and "cache_init" not in found["decode"]
+
+
+def test_lowered_programs_are_the_ones_that_run(setup):
+    """``ServeEngine.lower`` from shapes gives the same compiled programs
+    as the engine's own calls on arrays."""
+    cfg, _, engine = setup
+    prompt = jnp.zeros((2, 16), jnp.int32)
+    lowered = engine.lower(16)
+    _, cache = engine.prefill(prompt)
+    ran = {"prefill": engine._prefill.lower(engine.params, {"tokens": prompt}),
+           "decode": engine._decode.lower(engine.params, cache,
+                                          jnp.zeros((2, 1), jnp.int32),
+                                          jnp.int32(16))}
+    for name in ran:
+        assert (lowered[name].compile().as_text()
+                == ran[name].compile().as_text()), name
