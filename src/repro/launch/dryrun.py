@@ -62,13 +62,13 @@ def cache_shardings(policy, cspec):
         name = str(getattr(path[-1], "key", path[-1]))
         batch = policy.phys("batch")
         if name in ("k", "v"):
-            # (n_super, B, S, KH, hd): batch over data; model axis carries
+            # (n_super, B, KH, S, hd): batch over data; model axis carries
             # head_dim ("kvdim") or sequence ("kvseq") per policy.kv_layout.
             b = batch if _div(leaf.shape[1], policy, batch) else None
             if policy.kv_layout == "kvseq":
                 sq = (policy.model_axis
-                      if leaf.shape[2] % policy.model_size == 0 else None)
-                return NamedSharding(policy.mesh, P(None, b, sq, None, None))
+                      if leaf.shape[3] % policy.model_size == 0 else None)
+                return NamedSharding(policy.mesh, P(None, b, None, sq, None))
             hd = leaf.shape[-1]
             kvdim = policy.phys("kvdim") if hd % policy.model_size == 0 else None
             return NamedSharding(policy.mesh, P(None, b, None, None, kvdim))

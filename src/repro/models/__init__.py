@@ -8,4 +8,5 @@ from .model import (  # noqa: F401
     pipeline_fns,
     pipeline_param_parts,
     to_pipeline_params,
+    write_cache,
 )

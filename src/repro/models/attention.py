@@ -103,28 +103,38 @@ def blockwise_attention(q, k, v, *, chunk: int, causal: bool = True,
     return out.astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len):
-    """Single-token attention against a cache.
+def decode_attention(q, k_cache, v_cache, cache_len, k_new, v_new):
+    """Single-token attention against a cache and the token's own k/v.
 
-    q: (B, 1, H, hd); caches: (B, S_max, KH, hd); cache_len: () or (B,)
-    positions beyond cache_len are masked.  fp32 throughout.
+    q: (B, 1, H, hd); caches: (B, KH, S_max, hd), the order the two
+    contractions read, with slots ``< cache_len`` valid (cache_len: () or
+    (B,)); k_new, v_new: (B, KH, 1, hd), the token at slot ``cache_len``.
+    One softmax over the cached slots and the new token, fp32 throughout:
+    the cache is only read here (the caller writes the new token).
     """
     B, _, H, hd = q.shape
-    S, KH = k_cache.shape[1], k_cache.shape[2]
+    KH, S = k_cache.shape[1], k_cache.shape[2]
     group = H // KH
     scale = 1.0 / np.sqrt(hd)
     # Contract per KV head with the query group folded into the head dim:
     # no fp32 materialization of the cache (einsum accumulates fp32), no
     # grouped reshape of sharded dims.
     qf = q.reshape(B, KH, group, hd)
-    s = jnp.einsum("bkgh,bskh->bkgs", qf, k_cache,
+    s = jnp.einsum("bkgh,bksh->bkgs", qf, k_cache,
                    preferred_element_type=jnp.float32) * scale
+    s_new = jnp.einsum("bkgh,bksh->bkgs", qf, k_new,
+                       preferred_element_type=jnp.float32) * scale
     pos = jnp.arange(S)
     valid = pos[None, :] < jnp.reshape(cache_len, (-1, 1))     # (B or 1, S)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskh->bkgh", p.astype(q.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
+    m = jnp.maximum(s.max(axis=-1, keepdims=True), s_new)
+    p = jnp.exp(s - m)
+    p_new = jnp.exp(s_new - m)
+    l = p.sum(axis=-1, keepdims=True) + p_new
+    out = (jnp.einsum("bkgs,bksh->bkgh", (p / l).astype(q.dtype), v_cache,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgs,bksh->bkgh", (p_new / l).astype(q.dtype), v_new,
+                        preferred_element_type=jnp.float32))
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -171,7 +181,10 @@ def attention_block(p, x, cfg, policy, *, positions, mode, cache=None,
     """Full attention sub-layer: qkv proj -> rope -> attend -> out proj.
 
     x: (B, S, d).  Returns (out, new_cache).
-    In train/prefill ``cache`` is None / being built; in decode S == 1.
+    In train/prefill ``cache`` is None / being built (prefill returns the
+    prompt's k/v as (B, KH, S, hd)); in decode S == 1, ``cache`` is one
+    layer's (B, KH, S_max, hd) k/v, only read, and ``new_cache`` is the
+    new token's (B, KH, 1, hd) k/v for the caller to write at ``cache_len``.
     TP: heads sharded over the model axis (the paper's affine P_fo); under
     SP the incoming residual is seq-sharded and GSPMD inserts the
     seq->heads repartition (the paper's generalized all-to-all) — UNLESS
@@ -239,26 +252,27 @@ def attention_block(p, x, cfg, policy, *, positions, mode, cache=None,
                 out = blockwise_attention(q, k, v, chunk=cfg.attn_chunk,
                                           unroll=cfg.unroll_scans)
         if mode == "prefill":
+            # the cache's order (B, KH, S, hd): the one decode reads
+            k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)
             if policy is not None:
                 k = policy.constrain(k, "batch", None, None, "kvdim")
                 v = policy.constrain(v, "batch", None, None, "kvdim")
             new_cache = {"k": k, "v": v}
-    else:  # decode
+    else:  # decode: the layer reads its cache; model.forward writes the token
         assert cache is not None
-        idx = jnp.reshape(cache_len, ())
-        with jax.named_scope("attn_cache"):
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
-            if policy is not None:
+        k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)              # (B, KH, 1, hd)
+        k_cache, v_cache = cache["k"], cache["v"]
+        if policy is not None:
+            with jax.named_scope("attn_cache"):
                 if getattr(policy, "kv_layout", "kvdim") == "kvseq":
-                    k_cache = policy.constrain(k_cache, "batch", "kvseq", None, None)
-                    v_cache = policy.constrain(v_cache, "batch", "kvseq", None, None)
+                    k_cache = policy.constrain(k_cache, "batch", None, "kvseq", None)
+                    v_cache = policy.constrain(v_cache, "batch", None, "kvseq", None)
                 else:
                     k_cache = policy.constrain(k_cache, "batch", None, None, "kvdim")
                     v_cache = policy.constrain(v_cache, "batch", None, None, "kvdim")
         with jax.named_scope("attn_core"):
-            out = decode_attention(q, k_cache, v_cache, idx + 1)
-        new_cache = {"k": k_cache, "v": v_cache}
+            out = decode_attention(q, k_cache, v_cache, cache_len, k, v)
+        new_cache = {"k": k, "v": v}
 
     with jax.named_scope("attn_out"):
         out = out.reshape(out.shape[0], out.shape[1], cfg.num_heads * hd)

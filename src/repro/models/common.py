@@ -6,12 +6,14 @@ Named scopes.  The forward puts each of its sublayers under one flat
 
 - ``embed``: the token lookup (``model.forward``);
 - ``layers``: the scan over the stacked layers; the scan's own slicing of
-  the stacked weights and caches, its stacking of the new caches and its
-  loop counter carry no inner name;
+  the stacked weights and caches, its stacking of the new tokens' k/v (and
+  SSM states) and its loop counter carry no inner name;
+- ``attn_cache``: decode's one write of the new tokens' keys and values
+  into the stacked cache, after the scan (``model.forward``), and the
+  layer body's sharding constraints on the cache it reads;
 - in the layer body (``blocks.sublayer_apply``, ``attention.attention_block``):
   ``norm`` the two RMSNorms; ``attn_qkv`` the q/k/v projections;
-  ``attn_rope`` the rotary embedding; ``attn_cache`` decode's writes of the
-  new key and value into the cache; ``attn_core`` the attention contraction
+  ``attn_rope`` the rotary embedding; ``attn_core`` the attention contraction
   (blockwise, decode, flash or ring); ``attn_out`` the output projection
   and the residual add that joins it; ``ffn`` the dense MLP and its
   residual add;

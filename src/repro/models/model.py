@@ -218,7 +218,8 @@ def init_cache(cfg, batch: int, max_seq: int, dtype=None):
     def one(pos):
         kind = cfg.mixer_kind(pos)
         if kind == "attn":
-            shape = (n_super, batch, max_seq, cfg.num_kv_heads, hd)
+            # sequence next to head_dim: the order decode's contractions read
+            shape = (n_super, batch, cfg.num_kv_heads, max_seq, hd)
             return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         din = cfg.d_inner
         return {
@@ -228,6 +229,22 @@ def init_cache(cfg, batch: int, max_seq: int, dtype=None):
         }
 
     return {f"pos{i}": one(i) for i in range(cfg.block_period)}
+
+
+def write_cache(cache, new, start):
+    """``cache`` with ``new`` written in: each attention leaf (``k``, ``v``;
+    ``(n_super, B, KH, max_seq, hd)``) takes ``new``'s positions from slot
+    ``start`` on, in place where the cache is donated; the SSM leaves
+    (``conv``, ``ssm``) are small states, replaced whole."""
+    idx = jnp.reshape(start, ())
+
+    def write(path, old, src):
+        src = src.astype(old.dtype)
+        if path[-1].key in ("k", "v"):
+            return jax.lax.dynamic_update_slice_in_dim(old, src, idx, axis=3)
+        return src
+
+    return jax.tree_util.tree_map_with_path(write, cache, new)
 
 
 def forward(params, batch, cfg, policy=None, *, mode="train", cache=None,
@@ -271,6 +288,8 @@ def forward(params, batch, cfg, policy=None, *, mode="train", cache=None,
 
     # None-valued cache dict contributes no scan leaves (train/prefill build
     # caches from scratch); a real cache is stacked (n_super, ...) per pos.
+    # In decode the scan only reads it: the layers return the new token's
+    # k/v (and their whole SSM states), written after the scan.
     cache_xs = cache if cache is not None else {
         f"pos{i}": None for i in range(cfg.block_period)}
 
@@ -278,6 +297,9 @@ def forward(params, batch, cfg, policy=None, *, mode="train", cache=None,
         (x, aux), new_cache = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)), (params["blocks"], cache_xs),
             unroll=cfg.unroll_scans)
+    if mode == "decode":
+        with jax.named_scope("attn_cache"):
+            new_cache = write_cache(cache, new_cache, cache_len)
 
     with jax.named_scope("final_norm"):
         x = rmsnorm(x, params["norm_final"])

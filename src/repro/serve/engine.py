@@ -19,7 +19,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.models import forward, init_cache
+from repro.models import forward, init_cache, write_cache
 
 
 class ServeEngine:
@@ -41,16 +41,10 @@ class ServeEngine:
         logits, pref_cache, _ = forward(params, batch, self.cfg, self.policy,
                                         mode="prefill")
 
-        def write(dst, src):
-            if dst.ndim >= 3 and dst.shape[2] == self.max_seq:
-                return jax.lax.dynamic_update_slice_in_dim(dst, src.astype(dst.dtype),
-                                                           0, axis=2)
-            return src.astype(dst.dtype)   # ssm state / conv state: final
-
         with jax.named_scope("cache_init"):
             big = init_cache(self.cfg, self.batch_size, self.max_seq,
                              jnp.dtype(self.cfg.dtype))
-            cache = jax.tree_util.tree_map(write, big, pref_cache)
+            cache = write_cache(big, pref_cache, 0)
         with jax.named_scope("head"):
             return logits[:, -1], cache
 

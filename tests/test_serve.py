@@ -104,3 +104,71 @@ def test_lowered_programs_are_the_ones_that_run(setup):
     for name in ran:
         assert (lowered[name].compile().as_text()
                 == ran[name].compile().as_text()), name
+
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "jamba-v0.1-52b"])
+def test_decode_writes_each_token_in_its_slot(arch):
+    """Prefill S tokens, then N decode steps: the engine's cache holds the
+    k/v an uncached prefill over the S + N tokens gives, in slots
+    [0, S + N), zeros beyond, and the SSM states of that prefill.  The
+    experts' capacity holds every token, so that no token is dropped in
+    the longer prefill that is not dropped in the shorter one."""
+    import dataclasses
+
+    from repro.models import init_cache
+
+    cfg = reduced(get_config(arch))
+    cfg = dataclasses.replace(cfg, capacity_factor=float(max(cfg.num_experts, 1)))
+    params = init_params(cfg, jax.random.PRNGKey(8))
+    S, N, max_seq = 16, 5, 40
+    engine = ServeEngine(cfg, params, None, max_seq=max_seq, batch_size=2)
+    seq = jax.random.randint(jax.random.PRNGKey(9), (2, S + N), 0,
+                             cfg.vocab_size)
+    _, cache = engine.prefill(seq[:, :S])
+    for t in range(N):
+        _, cache = engine.decode_step(cache, seq[:, S + t:S + t + 1],
+                                      jnp.int32(S + t))
+    _, want, _ = forward(params, {"tokens": seq}, cfg, None, mode="prefill")
+
+    shapes = jax.tree_util.tree_map(jnp.shape, init_cache(cfg, 2, max_seq))
+    assert jax.tree_util.tree_map(jnp.shape, cache) == shapes
+    kinds = set()
+    for pos, leaves in cache.items():
+        for name, got in leaves.items():
+            got, ref = np.asarray(got), np.asarray(want[pos][name])
+            kinds.add(name)
+            if name in ("k", "v"):
+                np.testing.assert_allclose(got[:, :, :, :S + N], ref,
+                                           rtol=1e-2, atol=1e-2)
+                assert not got[:, :, :, S + N:].any()
+            else:
+                np.testing.assert_allclose(got, ref, rtol=1e-2, atol=1e-2)
+    assert kinds == ({"k", "v", "conv", "ssm"} if cfg.family == "hybrid"
+                     else {"k", "v"})
+
+
+def test_decode_scan_carries_only_new_tokens(setup):
+    """The layer scan of a decode step reads the stacked cache and hands
+    back only the new token's k/v: no output of the scan has the cache's
+    ``max_seq`` slots.  The cache is stored in the order the decode
+    contractions read, (n_super, B, KH, max_seq, hd)."""
+    from repro.models import init_cache
+
+    cfg, params, _ = setup
+    max_seq, B = 40, 2
+    engine = ServeEngine(cfg, params, None, max_seq=max_seq, batch_size=B)
+    cache = init_cache(cfg, B, max_seq)
+    n_super = cfg.num_layers // cfg.block_period
+    kv_heads, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    for leaf in jax.tree_util.tree_leaves(cache):
+        assert leaf.shape == (n_super, B, kv_heads, max_seq, hd)
+
+    jaxpr = jax.make_jaxpr(engine._decode_impl)(
+        params, cache, jnp.zeros((B, 1), jnp.int32), jnp.int32(3))
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    ins = [v.aval.shape for v in scans[0].invars]
+    outs = [v.aval.shape for v in scans[0].outvars]
+    assert ins.count((n_super, B, kv_heads, max_seq, hd)) == 2
+    assert not any(max_seq in shape for shape in outs)
+    assert outs.count((n_super, B, kv_heads, 1, hd)) == 2

@@ -113,9 +113,9 @@ def test_prefill_then_decode_matches_full_forward(arch):
                                    mode="prefill")
     # pad caches to S+8 max length
     def pad(l):
-        if l.ndim >= 3 and l.shape[2] == S:      # (n_super,B,S,kh,hd)
+        if l.ndim >= 4 and l.shape[3] == S:      # (n_super,B,kh,S,hd)
             pad_width = [(0, 0)] * l.ndim
-            pad_width[2] = (0, 8)
+            pad_width[3] = (0, 8)
             return jnp.pad(l, pad_width)
         return l
     cache = jax.tree_util.tree_map(pad, cache)
