@@ -1,7 +1,8 @@
 """Driver for serving cells: the program's ServeEngine, lockstep batches
 in a closed loop.
 
-Set-up makes the benchmark's seeded weights, builds one
+Set-up makes the benchmark's seeded weights with the reference of the
+configuration's family (``bench/family.py``), builds one
 ``repro.serve.ServeEngine`` over them and warms its programs with one
 batch of the cell's shapes (prefill, two decode steps, the greedy pick).
 The window then submits lockstep batches one after another, each as soon
@@ -15,12 +16,18 @@ Traffic parameters (``bench/traffic/<mix>.json``): ``batch`` requests
 per lockstep batch, ``prompt`` tokens each (uniform over the vocabulary),
 ``new_tokens`` greedy tokens each, ``max_seq`` cache slots.  The cell's
 file (``bench/workloads/<cell>.json``) holds ``check.requests`` and the
-``limits`` of the comparison.
+``limits`` of the comparison.  The record carries ``max_seq``, so that
+``bench/scopes.py`` compiles the engine's programs at the cell's slots.
 
 Correctness: once the window has closed and the engine is freed, a sample
 of ``check.requests`` finished requests, drawn from the seed, is run
 through the plain reference over prompt plus served tokens, and the widest
 gap between the reference's best logit and a served token's is compared.
+
+The ``window:`` line on standard error says where a run's time went: the
+batches, the token gaps, the longest prefill and the backend compiles that
+fell inside the window (none, once warm-up is complete), where the program
+logs its compiles.
 """
 
 from __future__ import annotations
@@ -34,8 +41,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import common, generator
-from bench.reference import dense_decoder as ref
+from bench import common, family, generator
+
+try:
+    from repro.launch.compile_cache import compiles, log_compiles
+except ImportError:           # a program from before its compile log
+    compiles = log_compiles = None
 
 # Each call's span reaches until its tokens are on the host, so the
 # device work it launched lies inside it; the fetch is nested in it.
@@ -47,7 +58,8 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
         trace: bool, t0: float, calibrate: str = ""):
     from repro.serve import ServeEngine
 
-    cfg = common.program_config(config)
+    ref, prog = family.load(config)
+    cfg = prog.program_config(config)
     spec = ref.Spec.from_config(config)
     t = traffic
     B, P, N = t["batch"], t["prompt"], t["new_tokens"]
@@ -57,7 +69,9 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
     key = generator.weights_key(seed)
     make = jax.jit(lambda k: ref.init_weights(spec, k))
 
-    engine = ServeEngine(cfg, common.to_program(make(key)), None,
+    if log_compiles is not None:
+        log_compiles()
+    engine = ServeEngine(cfg, prog.to_program(make(key)), None,
                          max_seq=t["max_seq"], batch_size=B)
 
     def pick(logits):
@@ -96,14 +110,22 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
         deadline = start + seconds
         while time.perf_counter() < deadline:
             batches.append(serve(len(batches) + 1, N, deadline, trace))
-    window_s = batches[-1]["times"][-1] - start
+    end = batches[-1]["times"][-1]
+    window_s = end - start
     gaps = np.concatenate([np.diff(b["times"]) for b in batches])
+    if compiles is None:
+        compiled = "not logged"
+    else:
+        inside = [c for c in compiles() if c[2] > start and c[1] < end]
+        compiled = f"{len(inside)}" + "".join(
+            f"; {name} at {s - start:.3f} s, {e - s:.3f} s"
+            for name, s, e in inside)
     # Printed so that a run reading far off shows where its time went.
     print(f"window: {len(batches)} batches, {window_s:.3f} s; token gaps "
           f"median {np.median(gaps) * 1e3:.2f} ms, longest "
           f"{gaps.max(initial=0) * 1e3:.2f} ms; longest prefill "
-          f"{max(b['times'][0] - b['submit'] for b in batches) * 1e3:.2f} ms",
-          file=sys.stderr)
+          f"{max(b['times'][0] - b['submit'] for b in batches) * 1e3:.2f} ms; "
+          f"compiles in the window: {compiled}", file=sys.stderr)
     peak = common.peak_bytes(device)
     del engine
     gc.collect()
@@ -130,7 +152,7 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
     rec = SimpleNamespace(
         config=config, cell=cell, device=device,
         setup_s=setup_s, window_s=window_s, trace=out.get("trace"),
-        batch=B, prompt=P, new_tokens=N, batches=batches,
+        batch=B, prompt=P, new_tokens=N, max_seq=t["max_seq"], batches=batches,
         tokens=sum(B * len(b["times"]) for b in batches),
         attempted=B * len(batches), failed=0, compared=len(widest),
         correct=correct, checks=checks, numbers=numbers, peak_bytes=peak)

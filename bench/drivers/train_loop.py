@@ -2,11 +2,12 @@
 
 Set-up builds one object, the launcher's jitted train step
 (``repro.train.build_train_step`` with the state donated, AdamW from
-``repro.optim``) with its state made from the benchmark's seeded weights,
-and drives it through ``repro.train.loop.run`` for its first three steps
-on the benchmark's token stream fed through the program's
-``PrefetchIterator``.  Those steps compile and warm the step, and are the
-steps the reference follows.  The same object then runs the window:
+``repro.optim``) with its state made from the benchmark's seeded weights
+(made by the reference of the configuration's family,
+``bench/family.py``), and drives it through ``repro.train.loop.run`` for
+its first three steps on the benchmark's token stream fed through the
+program's ``PrefetchIterator``.  Those steps compile and warm the step,
+and are the steps the reference follows.  The same object then runs the window:
 ``run`` again, for as many steps as fill ``--seconds`` at the warm step
 time, with no checkpoint and a silent logger.  Its per-step sync (block on
 the loss, fetch every metric) is part of the loop users run, so it is
@@ -29,8 +30,7 @@ from types import SimpleNamespace
 
 import jax
 
-from bench import common, compare, generator
-from bench.reference import dense_decoder as ref
+from bench import common, compare, family, generator
 
 CHECK_STEPS = 3
 STACKED_NORMS = ("attn_norm", "mlp_norm")      # (layers, d) in the program
@@ -60,11 +60,12 @@ def _hyper(opt: dict):
             opt["max_grad_norm"], opt["z_loss"])
 
 
-def _first_grad(m, b1):
+def _first_grad(prog, m, b1):
     """The first clipped gradient from AdamW's first moment after one
-    step, m_1 / (1 - b1), on the host in the reference's layout."""
+    step, m_1 / (1 - b1), on the host in the reference's layout (``prog``
+    is the family's program mapping)."""
     return jax.tree_util.tree_map(lambda x: x / (1.0 - b1),
-                                  common.from_program(jax.device_get(m)))
+                                  prog.from_program(jax.device_get(m)))
 
 
 def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
@@ -76,7 +77,8 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
     from repro.train.loop import run as train_run
     from repro.train.step import cross_entropy
 
-    cfg = common.program_config(config)
+    ref, prog = family.load(config)
+    cfg = prog.program_config(config)
     spec = ref.Spec.from_config(config)
     t, opt_p = traffic, traffic["optimizer"]
     z_default = inspect.signature(cross_entropy).parameters["z_loss"].default
@@ -90,7 +92,7 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
     make = lambda: init(key)
     opt = AdamW(lr=lambda count: opt_p["lr"], b1=opt_p["b1"], b2=opt_p["b2"],
                 eps=opt_p["eps"], weight_decay=opt_p["weight_decay"])
-    state = train_lib.init_train_state(cfg, common.to_program(make()), opt)
+    state = train_lib.init_train_state(cfg, prog.to_program(make()), opt)
     step = jax.jit(train_lib.build_train_step(
         cfg, None, opt, max_grad_norm=opt_p["max_grad_norm"]),
         donate_argnums=(0,))
@@ -104,10 +106,10 @@ def run(cell: dict, config: dict, traffic: dict, seed: int, seconds: float,
 
     try:
         state, hist = loop(state, step, it, 1)
-        grad = _first_grad(state["opt"]["m"], opt_p["b1"])
+        grad = _first_grad(prog, state["opt"]["m"], opt_p["b1"])
         state, hist = loop(state, step, it, CHECK_STEPS, hist)
         program = {"losses": [h["loss"] for h in hist], "grad": grad,
-                   "params": common.from_program(jax.device_get(state["params"]))}
+                   "params": prog.from_program(jax.device_get(state["params"]))}
         warm = statistics.median(h["sec"] for h in hist[1:])
         n_steps = max(1, round(seconds / warm))
         setup_s = time.perf_counter() - t0

@@ -3,20 +3,25 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from ``BENCHMARK.json``: a cell's entry names
-its configuration (``bench/configs/<config>.json``) and its traffic mix
-(``bench/traffic/<traffic>.json``, which names the driver that generates
-it, ``bench/drivers/<driver>.py``); the cell's own file
+its configuration (``bench/configs/<config>.json``, which names its
+family's reference and program mapping, ``bench/family.py``) and its
+traffic mix (``bench/traffic/<traffic>.json``, which names the driver
+that generates it, ``bench/drivers/<driver>.py``); the cell's own file
 (``bench/workloads/<cell>.json``) holds the limits of its correctness
 check; each metric is read by ``bench/metrics/<metric>.py``, or a
 quantity split by the end-to-end metric it moves (``<quantity>.<kind>``)
-by ``bench/metrics/<quantity>.py``.  A later change adds a cell, a configuration, a traffic mix or a metric by adding
-files and entries.
+by ``bench/metrics/<quantity>.py``.  A later change adds a cell, a
+configuration of any family, a traffic mix or a metric by adding files
+and entries.
 
 With ``--trace 0`` the run reports the cell's end-to-end metrics; with
 ``--trace 1`` its per-layer metrics, ``busy_s``/``window_s`` and the
-breakdown, from a profiler trace of the window.  The last line of standard
-output is one JSON object; the numbers that decided ``correct`` are the
-last lines of standard error and the last key of that object.
+breakdown, from a profiler trace of the window: the device operations
+that took most time, the longest idle gaps by host span, and the device
+seconds by named scope in each span (``bench/scopes.py``; null where the
+record gives none).  The last line of standard output is one JSON
+object; the numbers that decided ``correct`` are the last lines of
+standard error and the last key of that object.
 
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 non-zero before printing a result.
@@ -85,6 +90,7 @@ def chips(n: int):
 
 
 def result(bench: dict, workload: str, rec, trace: bool) -> dict:
+    from bench import scopes
     from bench.peaks import peaks
 
     rec.peaks = peaks(rec.device.device_kind)
@@ -103,7 +109,8 @@ def result(bench: dict, workload: str, rec, trace: bool) -> dict:
         device["busy_s"] = rec.trace.busy_s()
         device["window_s"] = rec.trace.window_s()
         line["breakdown"] = {"device_ops": rec.trace.device_ops(),
-                             "idle_gaps": rec.trace.idle_gaps()}
+                             "idle_gaps": rec.trace.idle_gaps(),
+                             "scopes": scopes.of(rec)}
     line["checks"] = rec.checks
     return line
 

@@ -24,10 +24,11 @@ scopes it was traced under.  So:
   and every nanosecond counts once.  The charges of one span add up to
   ``Trace.busy_in`` of that span.
 
-``of(rec)`` gives, for a serving cell's traced record, device seconds by
-label inside its ``prefill`` and inside its ``decode`` spans.  It compiles
-the engine's two programs from shapes to read their text, and gives
-``None`` where the program cannot lower itself that way or carries no
+``of(rec)`` gives, for a traced serving record that carries its cache
+slots (``max_seq``), device seconds by label inside its ``prefill`` and
+inside its ``decode`` spans.  It compiles the engine's two programs
+from shapes to read their text, and gives ``None`` for a record without
+slots, where the program cannot lower itself that way or carries no
 scope, and for a span where more than ``OTHER_MAX`` of the time is
 ``other`` (the compiled copy then is not the program that ran).  JAX's
 persistent cache leaves metadata out of its key, so a program cached
@@ -39,10 +40,8 @@ from.
 
 from __future__ import annotations
 
-import json
 import re
 from collections import defaultdict
-from pathlib import Path
 
 from bench.trace import clip, union
 
@@ -60,7 +59,6 @@ CACHE, WEIGHTS = "cache", "weights"
 # Largest share of a span's device time that may be ``other``: the greedy
 # pick's eager ops are ~0.1 % of a decode span on a v5e.
 OTHER_MAX = 0.01
-ROOT = Path(__file__).resolve().parent
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -214,51 +212,27 @@ def span_scopes(trace, span: str, hlo_text: str, kinds=None) -> dict:
     return dict(by)
 
 
-def cache_slots(rec):
-    """The cache slots (``max_seq``) of the cell this record ran, from its
-    entry in ``BENCHMARK.json``.  The record names no cell, so the cell is
-    the serving entry whose configuration, cell file and traffic shapes are
-    the record's: None when none is, an error when two are with different
-    slot counts."""
-    def load(kind, name):
-        return json.loads((ROOT / kind / f"{name}.json").read_text())
-
-    bench = json.loads((ROOT.parent / "BENCHMARK.json").read_text())
-    found = {}
-    for w in bench["workloads"]:
-        t = load("traffic", w["traffic"])
-        if (t.get("driver") == "serve_lockstep"
-                and (t["batch"], t["prompt"], t["new_tokens"])
-                == (rec.batch, rec.prompt, rec.new_tokens)
-                and load("configs", w["config"]) == rec.config
-                and load("workloads", w["name"]) == rec.cell):
-            found[w["name"]] = t["max_seq"]
-    if len(set(found.values())) > 1:
-        raise ValueError(f"the record matches cells with different cache "
-                         f"slots: {found}")
-    return next(iter(found.values()), None)
-
-
 def programs(rec):
     """The compiled text of the serve engine's two programs at the
-    record's shapes, ``{"prefill": text, "decode": text}``, and the shapes
-    the decode step moves (``moved_shapes``); or None."""
+    record's shapes and cache slots (``rec.max_seq``), ``{"prefill": text,
+    "decode": text}``, and the shapes the decode step moves
+    (``moved_shapes``); or None for a record that names no slots.  The
+    weights' shapes come from the family of the record's configuration
+    (``bench/family.py``)."""
     import jax
 
-    from bench import common, generator
-    from bench.reference import dense_decoder as ref
+    from bench import family, generator
     from repro.serve import ServeEngine
 
-    if not hasattr(ServeEngine, "lower"):
+    max_seq = getattr(rec, "max_seq", None)
+    if max_seq is None or not hasattr(ServeEngine, "lower"):
         return None
-    max_seq = cache_slots(rec)
-    if max_seq is None:
-        return None
+    ref, prog = family.load(rec.config)
     spec = ref.Spec.from_config(rec.config)
     params = jax.eval_shape(
-        lambda k: common.to_program(ref.init_weights(spec, k)),
+        lambda k: prog.to_program(ref.init_weights(spec, k)),
         generator.weights_key(0))
-    engine = ServeEngine(common.program_config(rec.config), params, None,
+    engine = ServeEngine(prog.program_config(rec.config), params, None,
                          max_seq=max_seq, batch_size=rec.batch)
     key = "jax_compilation_cache_include_metadata_in_key"
     before = getattr(jax.config, key)

@@ -24,10 +24,11 @@ def config(name):
     ("glm4-9b-8L", 2_873_167_872),
 ])
 def test_param_count_matches_program(name, expected):
-    from bench.common import program_config
+    from bench import family
     from repro.models import init_params
 
     c = config(name)
+    program_config = family.load(c)[1].program_config
     shapes = jax.eval_shape(lambda k: init_params(program_config(c), k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     program = sum(math.prod(x.shape) for x in jax.tree_util.tree_leaves(shapes))

@@ -3,6 +3,7 @@ of any name on hand-made intervals, and the whole report at a tiny size on
 the CPU, where XLA's threads stand for the device."""
 
 import functools
+import json
 
 import pytest
 
@@ -59,7 +60,6 @@ def cpu_run(monkeypatch):
 
     monkeypatch.setattr(cc, "enable_compile_cache", lambda: None)
     monkeypatch.setattr(T, "read", functools.partial(T.read, device_line=cpu_op_line))
-    monkeypatch.setattr(S, "cache_slots", lambda rec: 32)
     # At this size the greedy pick, another program, is a few per cent of
     # a step, where on the chip it is ~0.1 %.
     monkeypatch.setattr(S, "OTHER_MAX", 0.1)
@@ -89,3 +89,19 @@ def test_report_at_a_tiny_size(cpu_run):
     assert any(line.startswith("compiles: ") for line in lines)
     idle = sum(s for _, s in D.idle_causes(rec.trace, events, top=10**6))
     assert idle == pytest.approx(rec.trace.window_s() - rec.trace.busy_s(), rel=1e-6)
+
+
+def test_traced_line_carries_the_scopes(cpu_run, monkeypatch):
+    """The traced result line gives the device seconds by scope next to
+    the top operations and idle gaps, and the checks stay its last key."""
+    import bench.peaks
+
+    rec, _, _ = cpu_run
+    monkeypatch.setattr(bench.peaks, "peaks", lambda kind: {
+        "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+    line = harness.result(bench, "glm4-decode-b32", rec, True)
+    got = json.loads(json.dumps(line))["breakdown"]
+    assert set(got) == {"device_ops", "idle_gaps", "scopes"}
+    assert got["scopes"] == S.of(rec) and got["scopes"]["decode"]
+    assert list(line)[-1] == "checks"

@@ -3,25 +3,30 @@ chip skipped: a sound run is ``correct``, and each fault a cell can have,
 planted in the timed path underneath, makes it not correct under the
 cells' own limits; so does the control (the reference with float8
 operands in the program's place).  Also the command line without a chip,
-and the data files every entry of BENCHMARK.json needs."""
+the data files every entry of BENCHMARK.json needs, the family each
+configuration names (read from its file, a stub family included), the
+cache slots a serving record carries, and the compiles the window line
+counts."""
 
 import json
 import os
 import re
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import common
+from bench import common, family
 from bench import run as harness
 
 TINY = {"name": "tiny", "hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 1e6,
-        "rms_norm_eps": 1e-6, "torch_dtype": "bfloat16"}
+        "rms_norm_eps": 1e-6, "torch_dtype": "bfloat16",
+        "reference": "bench/reference/dense_decoder.py"}
 BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
 ENTRY = {w["name"]: w for w in BENCH["workloads"]}
 # The training cell's files, kept for the cell that is out of
@@ -49,11 +54,11 @@ def train(calibrate=""):
                                    "cell": {"limits": TRAIN_LIMITS}})
 
 
-def serve(calibrate=""):
+def serve(calibrate="", max_seq=32, **config):
     entry = ENTRY["glm4-prefill-4k"]
     traffic = dict(harness.load("traffic", entry["traffic"]), batch=2,
-                   prompt=16, new_tokens=8, max_seq=32)
-    config = dict(TINY, arch="glm4-9b", tie_word_embeddings=False)
+                   prompt=16, new_tokens=8, max_seq=max_seq)
+    config = dict(TINY, arch="glm4-9b", tie_word_embeddings=False, **config)
     return harness.run_cell(entry, 2**31 + 12, 0.5, False, calibrate=calibrate,
                             files={"config": config, "traffic": traffic})
 
@@ -169,3 +174,127 @@ def test_every_entry_has_its_files():
         for w in m["workloads"]:
             assert m["moves"] in {
                 x["name"] for x in harness.metric_names(BENCH, w, False)}
+
+
+STUB_REFERENCE = '''
+from bench.reference import dense_decoder as dense
+
+CALLED = set()
+
+
+class Spec:
+    @staticmethod
+    def from_config(config):
+        CALLED.add("Spec.from_config")
+        return dense.Spec.from_config(config)
+
+
+def init_weights(spec, key):
+    CALLED.add("init_weights")
+    return dense.init_weights(spec, key)
+
+
+def gaps_and_top(*args):
+    CALLED.add("gaps_and_top")
+    return dense.gaps_and_top(*args)
+'''
+STUB_MAPPING = '''
+from bench.programs import dense_decoder as dense
+
+CALLED = set()
+
+
+def program_config(config):
+    CALLED.add("program_config")
+    return dense.program_config(config)
+
+
+def to_program(w):
+    CALLED.add("to_program")
+    return dense.to_program(w)
+
+
+def from_program(params):
+    CALLED.add("from_program")
+    return dense.from_program(params)
+'''
+
+
+def test_a_family_is_read_from_its_configuration_file(tmp_path):
+    """A configuration whose ``reference`` names a module outside the
+    benchmark's directory is served through that module and the mapping
+    of the same stem beside it, and through no other."""
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "programs").mkdir()
+    (tmp_path / "reference" / "stub_decoder.py").write_text(STUB_REFERENCE)
+    (tmp_path / "programs" / "stub_decoder.py").write_text(STUB_MAPPING)
+    reference = str(tmp_path / "reference" / "stub_decoder.py")
+    rec = serve(reference=reference)
+    assert rec.correct, rec.checks
+    assert rec.compared > 0
+    ref, prog = family.load(rec.config)
+    assert ref.__file__ == reference
+    assert ref.CALLED == {"Spec.from_config", "init_weights", "gaps_and_top"}
+    assert prog.CALLED == {"program_config", "to_program"}
+
+
+def test_a_serving_record_carries_its_cache_slots():
+    from bench import scopes
+
+    rec = serve(max_seq=48)
+    assert rec.max_seq == 48
+    texts, kinds = scopes.programs(rec)
+    caches = [shape for shape, kind in kinds.items() if kind == scopes.CACHE]
+    assert caches and all(48 in shape for shape in caches)
+    assert set(texts) == set(scopes.SPANS)
+    slotless = SimpleNamespace(**{**vars(rec), "max_seq": None})
+    assert scopes.programs(slotless) is None
+
+
+@pytest.mark.parametrize("compiling", [False, True])
+def test_the_window_line_counts_compiles_in_the_window(monkeypatch, capfd,
+                                                       compiling):
+    """Warm-up compiles every program the window runs, so the window line
+    counts none; a decode step that compiles a new program every call is
+    counted."""
+    if compiling:
+        from repro.serve import ServeEngine
+        real = ServeEngine.decode_step
+
+        def compiles_each_call(self, cache, tokens, cache_len):
+            jax.jit(lambda x: x + 1)(jnp.zeros(3)).block_until_ready()
+            return real(self, cache, tokens, cache_len)
+
+        monkeypatch.setattr(ServeEngine, "decode_step", compiles_each_call)
+    serve()
+    lines = [x for x in capfd.readouterr().err.splitlines()
+             if x.startswith("window: ")]
+    assert len(lines) == 1
+    count = int(re.search(r"compiles in the window: (\d+)", lines[0]).group(1))
+    assert count > 0 if compiling else count == 0, lines[0]
+
+
+def test_every_configuration_names_a_family():
+    """The reference and the program mapping that each configuration file
+    names exist and export their contracts (bench/family.py); a
+    configuration with a training cell also exports the training
+    reference."""
+    training = {TRAIN["config"]} | {
+        w["config"] for w in BENCH["workloads"]
+        if harness.load("traffic", w["traffic"])["driver"] == "train_loop"}
+    for name in {c["name"] for c in BENCH["configs"]} | training:
+        config = harness.load("configs", name)
+        assert all(p.is_file() for p in family.paths(config)), name
+        ref, prog = family.load(config)
+        wanted = family.REFERENCE + (family.TRAINING if name in training else ())
+        assert all(callable(getattr(ref, n)) for n in wanted), name
+        assert callable(ref.Spec.from_config), name
+        assert all(callable(getattr(prog, n)) for n in family.MAPPING), name
+
+
+def test_the_dense_mapping_refuses_other_families():
+    config = dict(harness.load("configs", "glm4-9b-8L"), name="jamba",
+                  arch="jamba-v0.1-52b")
+    _, prog = family.load(config)
+    with pytest.raises(ValueError, match="covers dense SwiGLU models, not hybrid"):
+        prog.program_config(config)

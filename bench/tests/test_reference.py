@@ -17,17 +17,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import common, generator
+from bench import family, generator
 from bench import compare as C
 from bench.reference import dense_decoder as R
 
 TINY = {"name": "tiny", "hidden_size": 64, "intermediate_size": 96,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "num_hidden_layers": 2, "vocab_size": 384, "rope_theta": 10000.0,
-        "rms_norm_eps": 1e-6, "torch_dtype": "float32"}
+        "rms_norm_eps": 1e-6, "torch_dtype": "float32",
+        "reference": "bench/reference/dense_decoder.py"}
 TIED = dict(TINY, arch="phi4-mini-3.8b", tie_word_embeddings=True)
 UNTIED = dict(TINY, arch="glm4-9b", tie_word_embeddings=False)
 ROOT = Path(__file__).resolve().parents[2]
+# The program mapping, read as the harness reads it: through the family
+# the configuration names.
+P = family.load(TINY)[1]
 
 
 def all_logits(spec, w, tokens):
@@ -39,10 +43,10 @@ def all_logits(spec, w, tokens):
 def test_forward_matches_program(config):
     from repro.models import forward
 
-    cfg, spec = common.program_config(config), R.Spec.from_config(config)
+    cfg, spec = P.program_config(config), R.Spec.from_config(config)
     w = R.init_weights(spec, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0, spec.vocab)
-    got = forward(common.to_program(w), {"tokens": tokens}, cfg, None,
+    got = forward(P.to_program(w), {"tokens": tokens}, cfg, None,
                   mode="train")[0]
     want = all_logits(spec, w, tokens)
     scale = float(jnp.abs(want).max())
@@ -52,9 +56,9 @@ def test_forward_matches_program(config):
 def test_prefill_and_cached_decode_match_reference():
     from repro.serve import ServeEngine
 
-    cfg, spec = common.program_config(UNTIED), R.Spec.from_config(UNTIED)
+    cfg, spec = P.program_config(UNTIED), R.Spec.from_config(UNTIED)
     w = R.init_weights(spec, jax.random.PRNGKey(2))
-    engine = ServeEngine(cfg, common.to_program(w), None, max_seq=48,
+    engine = ServeEngine(cfg, P.to_program(w), None, max_seq=48,
                          batch_size=3)
     prompt = jnp.asarray(generator.prompts(5, 0, 3, 20, spec.vocab))
     tokens, logits = engine.generate(prompt, steps=10, return_logits=True)
@@ -72,7 +76,7 @@ def test_three_training_steps_match_program():
     import repro.train as train_lib
     from repro.optim.optimizers import AdamW
 
-    cfg, spec = common.program_config(TIED), R.Spec.from_config(TIED)
+    cfg, spec = P.program_config(TIED), R.Spec.from_config(TIED)
     hp = (1e-3, 0.9, 0.95, 1e-8, 0.1, 1.0, 1e-4)
     decay = json.loads((ROOT / "bench" / "traffic" / "train-b4s512.json")
                        .read_text())["optimizer"]["decay"]
@@ -83,7 +87,7 @@ def test_three_training_steps_match_program():
 
     opt = AdamW(lr=lambda c: hp[0], b1=hp[1], b2=hp[2], eps=hp[3],
                 weight_decay=hp[4])
-    state = train_lib.init_train_state(cfg, common.to_program(make()), opt)
+    state = train_lib.init_train_state(cfg, P.to_program(make()), opt)
     step = jax.jit(train_lib.build_train_step(cfg, None, opt,
                                               max_grad_norm=hp[5]))
     losses = []
@@ -93,8 +97,8 @@ def test_three_training_steps_match_program():
         if i == 0:
             grad = jax.tree_util.tree_map(
                 lambda x: x / 0.1,
-                common.from_program(jax.device_get(state["opt"]["m"])))
-    params = common.from_program(jax.device_get(state["params"]))
+                P.from_program(jax.device_get(state["opt"]["m"])))
+    params = P.from_program(jax.device_get(state["params"]))
     start = jax.device_get(make())
 
     ref = R.follow_training(spec, hp, decay, make, batches)

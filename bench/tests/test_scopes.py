@@ -3,7 +3,6 @@ a CPU run of a tiny jitted scan, on hand-made nested operations, in the
 new readers on made-up records, and against the serve engine's compiled
 programs, so that a renamed scope fails here and not only on the chip."""
 
-import json
 import time
 from types import SimpleNamespace
 
@@ -244,43 +243,7 @@ def test_sublayer_counts_add_up():
                 == dense_decoder.decode_bytes(GLM4, B, live))
 
 
-def test_cells_find_their_cache_slots():
-    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
-    for w in bench["workloads"]:
-        t = harness.load("traffic", w["traffic"])
-        rec = SimpleNamespace(batch=t["batch"], prompt=t["prompt"],
-                              new_tokens=t["new_tokens"],
-                              config=harness.load("configs", w["config"]),
-                              cell=harness.load("workloads", w["name"]))
-        assert S.cache_slots(rec) == t["max_seq"]
-        assert S.cache_slots(SimpleNamespace(**{**vars(rec), "batch": 3})) is None
-        assert S.cache_slots(SimpleNamespace(**{**vars(rec), "config": {}})) is None
-
-
-def test_cells_with_other_cache_slots_fail_loudly(tmp_path, monkeypatch):
-    """A second cell with the same configuration, cell file and traffic
-    shapes but other cache slots makes the lookup fail, not guess."""
-    for kind in ("configs", "workloads", "traffic"):
-        (tmp_path / "bench" / kind).mkdir(parents=True)
-    config, cell = {"name": "c"}, {"limits": {"max_gap": 1.0}}
-    cells = []
-    for name, slots in (("a", 64), ("b", 128)):
-        traffic = {"driver": "serve_lockstep", "batch": 2, "prompt": 8,
-                   "new_tokens": 4, "max_seq": slots}
-        (tmp_path / "bench" / "traffic" / f"t{name}.json").write_text(json.dumps(traffic))
-        (tmp_path / "bench" / "workloads" / f"{name}.json").write_text(json.dumps(cell))
-        cells.append({"name": name, "config": "c", "traffic": f"t{name}"})
-    (tmp_path / "bench" / "configs" / "c.json").write_text(json.dumps(config))
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps({"workloads": cells}))
-    monkeypatch.setattr(S, "ROOT", tmp_path / "bench")
-    rec = SimpleNamespace(batch=2, prompt=8, new_tokens=4, config=config, cell=cell)
-    with pytest.raises(ValueError, match="different cache slots"):
-        S.cache_slots(rec)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps({"workloads": cells[:1]}))
-    assert S.cache_slots(rec) == 64
-
-
-def test_engine_programs_carry_every_scope_the_readers_use(monkeypatch):
+def test_engine_programs_carry_every_scope_the_readers_use():
     """The readers' labels are in the serve engine's compiled programs,
     each in the program its reader looks in: the scopes, and the scan's
     slices of the cache and of the weights told apart by their shapes."""
@@ -291,8 +254,8 @@ def test_engine_programs_carry_every_scope_the_readers_use(monkeypatch):
     config = dict(GLM4, hidden_size=64, intermediate_size=128,
                   num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                   num_hidden_layers=2, vocab_size=512)
-    rec = SimpleNamespace(config=config, batch=2, prompt=16, new_tokens=8)
-    monkeypatch.setattr(S, "cache_slots", lambda rec: 32)
+    rec = SimpleNamespace(config=config, batch=2, prompt=16, new_tokens=8,
+                          max_seq=32)
     texts, kinds = S.programs(rec)
     found = {}
     for span, text in texts.items():
